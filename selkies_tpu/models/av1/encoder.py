@@ -13,8 +13,8 @@ TPU H.264 path proved out:
 
 * per-MB change classification against the previous capture — ON DEVICE
   (models/hybrid_frontend.py: jitted dirty-MB step + the H.264 path's
-  coarse ME voting for scroll hints) on PCIe-local accelerators, or
-  FramePrep's native memcmp (the XDamage analogue) on the relay;
+  coarse ME voting for scroll hints) on TPU backends, or FramePrep's
+  native memcmp (the XDamage analogue) elsewhere;
 * UNCHANGED frames never reach libaom at all: they encode as a 5-byte
   show_existing_frame temporal unit (spec 5.9.2) re-showing the slot
   the previous frame landed in. Which slot that is comes from parsing

@@ -2,8 +2,8 @@
 
 Converts captured BGRx frames to padded I420 planes on the host CPU and
 tracks per-band dirty state vs the previous capture. Rationale: the
-host↔device link (tunnel or PCIe) is the pipeline bottleneck
-(tools/profile_link.py) — uploading I420 is 2.7x less data than BGRx, and
+host↔device link carries every uploaded byte — uploading I420 is 2.7x
+less data than BGRx, and
 the dirty-band map feeds the encoder's static-frame fast path today (an
 unchanged capture encodes as an all-skip P slice with zero device work;
 partial-band uploads are the next step). The reference leans on

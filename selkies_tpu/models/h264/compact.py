@@ -5,7 +5,7 @@ wraps them as FrameCoeffs / PFrameCoeffs, so the CAVLC packers are fed
 bit-identical inputs to the dense path (tests assert exact equality).
 Cost: a boolean unpack over M*26 flags + one fancy-index scatter of the
 nonzero rows — a few ms at 1080p, far below the 6.4 MB dense fetch it
-replaces on the tunnel/PCIe.
+replaces.
 """
 
 from __future__ import annotations

@@ -8,7 +8,8 @@ ONE kernel that keeps each MB row's reference window resident in VMEM:
     once (the XLA scans re-read the full padded plane from HBM for every
     one of the ~76 candidates — the dominant cost of the device step);
   * per-candidate SAD reduces 16x16 blocks via an MXU matmul against a
-    0/1 block-indicator matrix (f32 exact: SAD*scale + rank < 2^23);
+    0/1 block-indicator matrix (bf16 halves, exact: SAD*scale + rank
+    < 2^23);
   * cost argmin and prediction selection fuse into the same candidate
     loop — a running min with payload blend, so the winner's luma and
     half-pel chroma prediction are produced in the same pass.
@@ -88,6 +89,7 @@ def _me_mc_kernel(cand_ref, cur_ref, ry_ref, ru_ref, rv_ref, m_ref, mt_ref,
     G = _CAND_GROUP
     n_groups = ncand // G
 
+    m_bf = m_ref[:].astype(jnp.bfloat16)  # 0/1 block-indicator, exact
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (G * 16, _LUMA_WIN), 0)
     col_iota = jax.lax.broadcasted_iota(jnp.int32, (G * 16, _LUMA_WIN), 1)
     row_iota9 = jax.lax.broadcasted_iota(jnp.int32, (9, _CHROMA_WIN), 0)
@@ -111,8 +113,14 @@ def _me_mc_kernel(cand_ref, cur_ref, ry_ref, ru_ref, rv_ref, m_ref, mt_ref,
                             wp - MV_PAD - dxs[k], 1)[:, 0:w]
             shs.append(sh)
             rowsums.append(jnp.sum(jnp.abs(cur - sh), axis=0, keepdims=True))
-        rs = jnp.concatenate(rowsums, axis=0)  # (G, w)
-        mbsum = jnp.dot(rs, m_ref[:], preferred_element_type=jnp.float32)  # (G, 128)
+        rs = jnp.concatenate(rowsums, axis=0).astype(jnp.int32)  # (G, w)
+        # per-MB SAD = rs x block matrix. The MXU multiplies in bf16,
+        # exact only for integers <= 256, and a column sum reaches
+        # 16*255: split it into two exact bf16 halves, rs = 256*hi + lo
+        hi = jnp.right_shift(rs, 8).astype(jnp.float32).astype(jnp.bfloat16)
+        lo = jnp.bitwise_and(rs, 255).astype(jnp.float32).astype(jnp.bfloat16)
+        mbsum = 256.0 * jnp.dot(hi, m_bf, preferred_element_type=jnp.float32) \
+            + jnp.dot(lo, m_bf, preferred_element_type=jnp.float32)  # (G, 128)
 
         for k in range(G):
             c = c0 + k

@@ -17,8 +17,7 @@ class LinkByteCounter:
     device->host. Incremented from the dispatch thread AND the
     completion workers, hence the lock. bench.py and
     tools/profile_link_bytes.py read snapshots around a timed pass to
-    report bytes/frame per direction — the quantity the relay actually
-    prices (PERF.md cost model)."""
+    report bytes/frame per direction."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()

@@ -15,8 +15,8 @@ H.264 path proved out:
 * per-MB change classification against the previous capture — ON DEVICE
   (models/hybrid_frontend.py: a jitted dirty-MB step plus the H.264
   path's coarse_vote_candidates_jnp ME voting for scroll hints) on
-  PCIe-local accelerators, or FramePrep's native memcmp (the XDamage
-  analogue) on the relay, where frame upload is per-byte priced;
+  TPU backends, or FramePrep's native memcmp (the XDamage analogue)
+  elsewhere;
 * UNCHANGED frames never reach libvpx at all: they encode as a ONE-BYTE
   VP9 `show_existing_frame` header (uncompressed header only, no
   compressed data, so no bool coder involved) re-showing the last

@@ -276,3 +276,47 @@ def scope(trigger: str, detail: str = ""):
 def stats() -> dict:
     """The active sentinel's stats (the /statz ``compile`` provider)."""
     return (_active or sentinel).stats()
+
+
+# -- compiles in flight -------------------------------------------------------
+# The supervisor's tick-deadline watchdog (resilience/supervisor.py) reads
+# compiling(): a tick blocked in an XLA compile — the lazy first use of an
+# executable, ~100 s for a device-entropy step built for v5e — is not a
+# wedged device. JAX reports a backend compile's start (a scalar event)
+# and its end (a duration event) under the same name.
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_inflight = 0
+_tracking = False
+
+
+def _compile_started(event: str, _value, **_kw) -> None:
+    global _inflight
+    if event == _COMPILE_EVENT:
+        with _reg_lock:
+            _inflight += 1
+
+
+def _compile_ended(event: str, _duration: float, **_kw) -> None:
+    global _inflight
+    if event == _COMPILE_EVENT:
+        with _reg_lock:
+            _inflight = max(0, _inflight - 1)
+
+
+def track_compiles() -> None:
+    """Register the listeners behind :func:`compiling`, once per process."""
+    global _tracking
+    with _reg_lock:
+        if _tracking:
+            return
+        import jax.monitoring as jm
+
+        jm.register_scalar_listener(_compile_started)
+        jm.register_event_duration_secs_listener(_compile_ended)
+        _tracking = True
+
+
+def compiling() -> bool:
+    """Whether an XLA backend compile is running in this process."""
+    return _inflight > 0

@@ -112,7 +112,6 @@ from selkies_tpu.models.h264.numpy_ref import COARSE_R, MV_PAD, PFrameCoeffs
 from selkies_tpu.models.stats import FrameStats, LinkByteCounter
 from selkies_tpu.monitoring.telemetry import telemetry
 from selkies_tpu.monitoring.tracing import tracer
-from selkies_tpu.parallel.sessions import _CHECK_KW, _shard_map
 from selkies_tpu.resilience.devhealth import (
     check_device_faults,
     get_device_pool,
@@ -758,7 +757,6 @@ class BandedH264Encoder:
         from selkies_tpu.models.frameprep import FramePrep
 
         self._prep = FramePrep(width, height, self._pad_w, self._pad_h, nslots=2)
-        kw = {_CHECK_KW: False} if _CHECK_KW else {}
         # 1D band-step constants (unused by the cols > 1 tile branch,
         # but built once so the mesh and fallback band paths can never
         # compile against different constants)
@@ -775,14 +773,15 @@ class BandedH264Encoder:
                 self.mesh = tile_mesh(self.bands, self.cols, devs)
                 self._shard = NamedSharding(self.mesh, P("band", "col"))
                 spec = P("band", "col")
-                self._step_i = jax.jit(_shard_map(
+                self._step_i = jax.jit(jax.shard_map(
                     partial(_mesh_tile_i_body, **ticonsts), mesh=self.mesh,
-                    in_specs=(spec, spec, spec, P()), out_specs=spec, **kw))
+                    in_specs=(spec, spec, spec, P()), out_specs=spec,
+                    check_vma=False))
                 self._step_p = jax.jit(
-                    _shard_map(
+                    jax.shard_map(
                         partial(_mesh_tile_p_body, **tpconsts), mesh=self.mesh,
                         in_specs=(spec, spec, spec, P(), spec, spec, spec),
-                        out_specs=spec, **kw),
+                        out_specs=spec, check_vma=False),
                     donate_argnums=(4, 5, 6))
             else:
                 logger.info(
@@ -803,14 +802,15 @@ class BandedH264Encoder:
             self.mesh = band_mesh(self.bands, devs)
             self._shard = NamedSharding(self.mesh, P("band"))
             spec = P("band")
-            self._step_i = jax.jit(_shard_map(
+            self._step_i = jax.jit(jax.shard_map(
                 partial(_mesh_i_body, **iconsts), mesh=self.mesh,
-                in_specs=(spec, spec, spec, P()), out_specs=spec, **kw))
+                in_specs=(spec, spec, spec, P()), out_specs=spec,
+                check_vma=False))
             self._step_p = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     partial(_mesh_p_body, **pconsts), mesh=self.mesh,
                     in_specs=(spec, spec, spec, P(), spec, spec, spec),
-                    out_specs=spec, **kw),
+                    out_specs=spec, check_vma=False),
                 donate_argnums=(4, 5, 6))
         else:
             if self.bands > 1:

@@ -143,7 +143,6 @@ class MeshDeltaFrontend:
             select_coarse_jnp,
         )
         from selkies_tpu.ops.colorspace import bgrx_to_i420
-        from selkies_tpu.parallel.sessions import _CHECK_KW, _shard_map
 
         if devices is None:
             # single source of chip enumeration (resilience/devhealth):
@@ -190,14 +189,14 @@ class MeshDeltaFrontend:
             f = jnp.zeros((pad_h, pad_w, 4), jnp.uint8)
             f = f.at[: frame.shape[0], : frame.shape[1]].set(frame)
             f = jax.lax.with_sharding_constraint(f, self._frame_sharding)
-            return _shard_map(
+            return jax.shard_map(
                 col_body,
                 mesh=self._mesh,
                 in_specs=(P(None, "col", None), P(None, "col", None),
                           P(None, "col")),
                 out_specs=(P(None, "col"), P(), P(None, "col", None),
                            P(None, "col")),
-                **({_CHECK_KW: False} if _CHECK_KW else {}),
+                check_vma=False,
             )(f, prev, prev_luma)
 
         self._step = jax.jit(step, donate_argnums=(1, 2))

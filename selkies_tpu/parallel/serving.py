@@ -63,7 +63,10 @@ class MultiSessionH264Service:
         # service rebuilds (the fleet supervisor's RESTART rung) reload the
         # sharded step from the disk cache instead of recompiling
         enable_persistent_compilation_cache()
-        self.enc = MultiSessionEncoder(n_sessions, width, height, devices=devices)
+        # the device step runs on MB-aligned planes; the SPS crops the
+        # padding back off (1080p encodes as 1088 rows)
+        pad_w, pad_h = (width + 15) // 16 * 16, (height + 15) // 16 * 16
+        self.enc = MultiSessionEncoder(n_sessions, pad_w, pad_h, devices=devices)
         self.n = n_sessions
         # per-session IDR flags of the most recent tick (the serving loop
         # needs them for keyframe framing + VBV accounting, mirroring the
@@ -80,15 +83,15 @@ class MultiSessionH264Service:
         # native converter per session, run concurrently on the pack pool
         # — removes the ~14 ms/tick on-device colorspace + padded-frame
         # cost that held the mixed tick at ~43 fps/session (PERF.md)
-        self._preps = [FramePrep(width, height, width, height, nslots=2)
+        self._preps = [FramePrep(width, height, pad_w, pad_h, nslots=2)
                        for _ in range(n_sessions)]
         # persistent batch planes: workers copy each session's converted
         # planes into its slice, avoiding a fresh np.stack allocation
         # every tick (~4.5 MB/session of alloc+copy at 1080p); the
         # remaining host->device copy is the sharded device_put itself
-        self._batch_y = np.empty((n_sessions, height, width), np.uint8)
-        self._batch_u = np.empty((n_sessions, height // 2, width // 2), np.uint8)
-        self._batch_v = np.empty((n_sessions, height // 2, width // 2), np.uint8)
+        self._batch_y = np.empty((n_sessions, pad_h, pad_w), np.uint8)
+        self._batch_u = np.empty((n_sessions, pad_h // 2, pad_w // 2), np.uint8)
+        self._batch_v = np.empty((n_sessions, pad_h // 2, pad_w // 2), np.uint8)
         # the session mesh's chips, for the device:<chip> fault site —
         # a seeded schedule can kill/wedge/flap one chip of the lockstep
         # batch mid-stream (resilience/devhealth.py)

@@ -27,30 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.5 promotes shard_map to jax.shard_map, and separately renames
-# the replication-check kwarg check_rep -> check_vma; older runtimes only
-# ship the experimental one. Bind whichever exists and pick the kwarg by
-# SIGNATURE (intermediate releases pair jax.shard_map with check_rep), so
-# a fleet host on any jax generation runs the same code.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-def _shard_map_check_kw() -> str | None:
-    import inspect
-
-    try:
-        params = inspect.signature(_shard_map).parameters
-    except (ValueError, TypeError):
-        return None
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return kw
-    return None
-
-_CHECK_KW = _shard_map_check_kw()
-
 from selkies_tpu.models.h264.encoder_core import (
     encode_frame_p_planes,
     encode_frame_planes,
@@ -186,13 +162,13 @@ class MultiSessionEncoder:
         spec = P("session")
         n_in_m = 8 if self.host_convert else 6
         self._step_mixed = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 mixed, mesh=self.mesh,
                 in_specs=(spec,) * n_in_m, out_specs=spec,
                 # the encode scans carry replicated-initialized state that
                 # becomes device-varying after one step; skip the varying-
                 # axis type check (every input/output is fully sharded)
-                **({_CHECK_KW: False} if _CHECK_KW else {}),
+                check_vma=False,
             ),
             donate_argnums=tuple(range(n_in_m - 3, n_in_m)),
         )

@@ -117,8 +117,8 @@ PRESETS: dict[str, dict[Scenario, KnobPlan]] = {
     "balanced": _BALANCED,
     # latency: never wait for a group — every scenario dispatches singles
     "latency": _with_batch(_BALANCED, BATCH_MIN),
-    # throughput: always fill full groups (relay-priced links where round
-    # trips dominate and added frames of latency are acceptable)
+    # throughput: always fill full groups (round trips dominate and added
+    # frames of latency are acceptable)
     "throughput": _with_batch(_BALANCED, BATCH_MAX),
 }
 

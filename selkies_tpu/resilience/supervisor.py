@@ -26,9 +26,11 @@ from __future__ import annotations
 
 import enum
 import logging
+import sys
 import time
 from typing import Callable, Protocol
 
+from selkies_tpu.monitoring import jitprof
 from selkies_tpu.monitoring.telemetry import telemetry
 
 logger = logging.getLogger("resilience.supervisor")
@@ -163,6 +165,10 @@ class SlotSupervisor:
         # slot's flight-recorder ring (monitoring/flightrecorder.py)
         self.on_escalation: Callable[[Rung, str], None] | None = None
         telemetry.register_slot(name, self)  # /healthz visibility
+        if "jax" in sys.modules:
+            # a slot without jax never compiles; with it, the watchdog
+            # must see compiles that start before its first check
+            jitprof.track_compiles()
 
     def _emit(self, event: str) -> None:
         """Fold a ladder event into the telemetry counters (one attribute
@@ -311,11 +317,19 @@ class SlotSupervisor:
         """Watchdog: no healthy tick for ``deadline_ticks`` tick intervals
         counts as a failure even though nothing raised (wedged device call,
         stalled capture thread). Fires at most once per deadline window.
-        Armed only after ``arm_after`` lifetime healthy ticks so first-use
-        jit compiles (tens of seconds on the CPU mesh) don't trip it."""
+        Armed only after ``arm_after`` lifetime healthy ticks, and held
+        while an XLA compile runs in the process, so first-use jit
+        compiles don't trip it."""
         now = self.clock() if now is None else now
         if self._total_ok < self.arm_after:
             return False
+        if "jax" in sys.modules:
+            jitprof.track_compiles()
+            if jitprof.compiling():
+                # a lazily built executable's first use blocks the tick
+                # for the whole compile; that is not a stall
+                self.last_ok = now
+                return False
         if now - self.last_ok <= self.deadline_ticks / self.fps:
             return False
         self.counters["deadline_misses"] += 1

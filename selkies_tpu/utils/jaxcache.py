@@ -8,46 +8,42 @@ the disk cache, a rebuilt program with identical HLO loads in a fraction
 of the time, and a restarted *process* (supervisor-level recovery, CI
 reruns) warm-starts too.
 
-``SELKIES_JAX_CACHE`` controls it: unset/``1``/``on`` → enabled under the
-system temp dir; a path → enabled there; ``0``/``off`` → disabled.
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+module sets no other directory. Otherwise the cache lives at
+``.jax_cache/`` in the checkout root: a fixed path, because the path is
+part of what makes a later run find the entries again.
 """
 
 from __future__ import annotations
 
 import logging
 import os
-import tempfile
+from pathlib import Path
 
 logger = logging.getLogger("utils.jaxcache")
+
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 _done = False
 
 
+def cache_dir(environ=os.environ) -> str:
+    """The directory the persistent cache uses under ``environ``."""
+    return environ.get("JAX_COMPILATION_CACHE_DIR") or str(CHECKOUT_CACHE_DIR)
+
+
 def enable_persistent_compilation_cache() -> None:
-    """Idempotent; call before building jitted programs. Failures degrade
-    to uncached compiles — never to a crash."""
+    """Idempotent; call before building jitted programs."""
     global _done
     if _done:
         return
     _done = True
-    mode = os.environ.get("SELKIES_JAX_CACHE", "1").strip()
-    if mode.lower() in ("0", "off", "false", ""):
-        logger.info("persistent compilation cache disabled (SELKIES_JAX_CACHE)")
-        return
-    path = (mode if mode.lower() not in ("1", "on", "true")
-            else os.path.join(tempfile.gettempdir(), "selkies-tpu-jax-cache"))
-    try:
-        import jax
+    import jax
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        try:
-            # cache everything that takes real time; tiny programs stay
-            # in-memory only
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        except Exception:
-            logger.info("jax build without min-compile-time knob; using defaults")
-        logger.info("persistent compilation cache at %s", path)
-    except Exception:
-        logger.exception("persistent compilation cache unavailable; "
-                         "compiles will not be reused across restarts")
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", cache_dir())
+    # cache everything that takes real time; tiny programs stay
+    # in-memory only
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    logger.info("persistent compilation cache at %s",
+                jax.config.jax_compilation_cache_dir)

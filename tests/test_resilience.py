@@ -227,6 +227,29 @@ def test_note_idle_suppresses_deadline():
     assert not sup.check_deadline()
 
 
+def test_compile_in_flight_holds_deadline():
+    """A tick blocked in an XLA compile (JAX reports its start as a
+    scalar event, its end as a duration event) is not a stall."""
+    import jax.monitoring as jm
+
+    from selkies_tpu.monitoring import jitprof
+
+    sup, acts, clock = make_supervisor(arm_after=1, deadline_ticks=30.0)
+    sup.tick_ok()
+    event = "/jax/core/compile/backend_compile_duration"
+    jm.record_scalar(event, clock())
+    try:
+        assert jitprof.compiling()
+        clock.advance(100.0)
+        assert not sup.check_deadline()
+    finally:
+        jm.record_event_duration_secs(event, 100.0)
+    assert not jitprof.compiling()
+    assert sup.counters["deadline_misses"] == 0 and acts.names() == []
+    clock.advance(2.0)  # a stall after the compile still counts
+    assert sup.check_deadline()
+
+
 # -- fault injection ---------------------------------------------------
 
 

@@ -438,6 +438,9 @@ def test_retune_entropy_loop_flags_recompile_storm(tele, tmp_path):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
     s = jitprof.CompileSentinel(storm_n=3, storm_window_s=600.0,
                                 startup_grace_s=0.0)
+    # the ring drops events older than its 10 s window on every record;
+    # on a loaded host the loop's later compiles can outlast it
+    tele.recorder.window_s = 600.0
     enc = None
     try:
         enc = TPUH264Encoder(192, 128, qp=28, frame_batch=1,

@@ -7,8 +7,7 @@ split-frame path.
 
 Runs anywhere: with no real TPU it forces an 8-device CPU host mesh
 (the same trick tests/conftest.py uses), so band scaling is measurable
-in CI containers; run it on hardware via tools/run_on_chip.sh for the
-numbers that go into PERF.md. Prints one human line per shape plus
+in CI containers; on hardware it measures the chips JAX finds. Prints one human line per shape plus
 bench.py-shaped JSON lines (the same shape tools/profile_pack.py's
 summary feeds the PERF record with):
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Characterize the host<->TPU link: bandwidth vs latency, both directions,
 various sizes — decides whether the encoder must minimize bytes/frame
-(bandwidth-limited tunnel) or round trips (latency-limited)."""
+(bandwidth-limited) or round trips (latency-limited)."""
 
 import sys
 import time

@@ -7,8 +7,7 @@ packed downlink ON vs OFF, and reports bytes/frame per stage
 (up_full / up_delta / up_ltr, down_prefix / down_refetch / down_spill,
 plus down_bits / down_bits_refetch / down_bits_spill when device
 entropy ships final slice bits — docs/device_entropy.md)
-plus the reduction ratios — the terms the relay prices per byte
-(PERF.md cost model). This is the measurement backing the ISSUE-1
+plus the reduction ratios. This is the measurement backing the ISSUE-1
 acceptance criteria (>=2x uplink cut on scroll, >=2x prefix-fetch cut
 on desktop).
 
@@ -16,7 +15,7 @@ Usage:
   JAX_PLATFORMS=cpu python tools/profile_link_bytes.py [--width W]
       [--height H] [--frames N] [--traces scroll,window,desktop]
 
-Byte counts are deterministic (they measure layout, not the tunnel), so
+Byte counts are deterministic (they measure layout, not the link), so
 the CPU backend gives the same numbers the chip would.
 """
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Microbenchmark of the host completion path (downlink bytes -> RBSP)
-over synthetic sparse buffers — no device or relay tunnel in the loop,
+over synthetic sparse buffers — no device in the loop,
 so completion regressions are measurable anywhere.
 
 Compares, per density/geometry/layout:
