@@ -255,6 +255,7 @@ def _shift_inc(grid):
     return left + 2 * top
 
 
+@jax.named_scope("enc.entropy.structure")
 def _cabac_structure(out):
     """_frame_structure + the CABAC context columns, all full-grid
     elementwise work (the cheap pass). New per-MB keys, each compactable
@@ -345,6 +346,7 @@ CABAC_COMPACT_KEYS = (
 )
 
 
+@jax.named_scope("enc.entropy.emit")
 def _emit_slice_tokens(s, word_cap: int):
     """The expensive half over a compacted structure: tokenize every
     block + header, pack each MB's 27 segments (header, 16 luma, 2
@@ -425,11 +427,14 @@ def pack_p_slice_tokens_active(out, word_cap: int | None = None,
     if len(buckets) == 1:
         words, ntok, counts = _run(buckets[0])
     else:
-        idx = jnp.clip(
-            jnp.searchsorted(jnp.asarray(buckets, jnp.int32), ns, side="left"),
-            0, len(buckets) - 1)
-        words, ntok, counts = jax.lax.switch(
-            idx, [(lambda _, A=b: _run(A)) for b in buckets], jnp.int32(0))
+        with jax.named_scope("enc.entropy.emit"):
+            idx = jnp.clip(
+                jnp.searchsorted(jnp.asarray(buckets, jnp.int32), ns,
+                                 side="left"),
+                0, len(buckets) - 1)
+            words, ntok, counts = jax.lax.switch(
+                idx, [(lambda _, A=b: _run(A)) for b in buckets],
+                jnp.int32(0))
     return words, ntok, counts, ns
 
 

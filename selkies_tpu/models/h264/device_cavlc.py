@@ -207,7 +207,7 @@ def _lut(idx, pair: np.ndarray):
 
     pair: (N, 2) np table. Per-element gathers price ~17 ns on v5e — a
     (B, 15) run_before lookup pair costs 30+ ms as a gather and ~1 ms as
-    an MXU contraction (tools/profile_device_entropy.py). f32 is exact for
+    an MXU contraction (measured on an earlier setup). f32 is exact for
     every VLC value (< 2^24)."""
     n = pair.shape[0]
     flat = idx.reshape(-1)
@@ -350,7 +350,7 @@ def _encode_blocks(coeffs, nc, chroma_dc: bool):
     # depends only on (level, suffix_len_before, is_first), so it runs
     # ONCE vectorized over all (L, B) slots. The L-step walk is UNROLLED
     # in Python: a lax.scan at this width pays ~1.5 ms of per-step launch
-    # overhead on v5e (tools/profile_device_entropy.py) while the unrolled
+    # overhead on v5e (measured on an earlier setup) while the unrolled
     # form fuses into a handful of kernels.
     init_sl = jnp.where((total > 10) & (t1 < 3), 1, 0)
     val_t = val_rev.T  # (L, B)
@@ -567,6 +567,7 @@ def _nc_grid(grid):
         jnp.where(has_l, left, jnp.where(has_t, top, 0)))
 
 
+@jax.named_scope("enc.entropy.structure")
 def _frame_structure(out):
     """Full-grid per-MB syntax structure — the CHEAP half of the coder.
 
@@ -629,7 +630,7 @@ def _frame_structure(out):
     # luma: MBs x 16 blocks in coding order. Block reorder as a STATIC
     # take over the 16-block axis: the equivalent multi-array fancy
     # gather lowers to a general gather that costs ~200 ms/frame on v5e
-    # (tools/profile_device_entropy.py); nC likewise comes from the
+    # (measured on an earlier setup); nC likewise comes from the
     # elementwise grid (_nc_grid) statically re-laid into coding order.
     ox, oy = jnp.asarray(_LUMA_ORDER)[:, 0], jnp.asarray(_LUMA_ORDER)[:, 1]
     luma_perm = jnp.asarray(
@@ -730,6 +731,7 @@ _COMPACT_KEYS = (
 )
 
 
+@jax.named_scope("enc.entropy.compact")
 def _compact_structure(s, A: int, keys=_COMPACT_KEYS):
     """Gather the coded MBs of a frame structure into a dense prefix of
     `A` padded slots (raster order preserved; slots past the coded count
@@ -751,6 +753,7 @@ def _compact_structure(s, A: int, keys=_COMPACT_KEYS):
     return {k: cp(s[k]) for k in keys}
 
 
+@jax.named_scope("enc.entropy.emit")
 def _emit_slice_bits(s, word_cap: int):
     """The EXPENSIVE half: VLC-encode every block of a (possibly
     compacted) per-MB structure, pack each segment's codewords into bit
@@ -807,7 +810,9 @@ def pack_p_slice_bits(out, word_cap: int = WORD_CAP_DEFAULT):
     header and the final skip_run — the host splices it after its own
     header bits and finishes the NAL. Production paths use
     pack_p_slice_bits_active; this fixed-shape form remains the oracle
-    for tests and the cost baseline for tools/profile_device_entropy.py.
+    for tests. A device trace splits the step's cost by the named
+    scopes of the two halves (enc.entropy.structure / .compact / .emit,
+    monitoring/tracing.py).
     """
     s = _frame_structure(out)
     words, nbits = _emit_slice_bits(s, word_cap)
@@ -852,11 +857,14 @@ def pack_p_slice_bits_active(out, word_cap: int = WORD_CAP_DEFAULT,
             return lambda _: _emit_slice_bits(s, word_cap)
         return lambda _: _emit_slice_bits(_compact_structure(s, A), word_cap)
 
-    idx = jnp.clip(
-        jnp.searchsorted(jnp.asarray(buckets, jnp.int32), ns, side="left"),
-        0, len(buckets) - 1)
-    words, nbits = jax.lax.switch(idx, [_branch(b) for b in buckets],
-                                  jnp.int32(0))
+    # the bucket switch counts as emission; each branch's compaction
+    # carries its own scope
+    with jax.named_scope("enc.entropy.emit"):
+        idx = jnp.clip(
+            jnp.searchsorted(jnp.asarray(buckets, jnp.int32), ns, side="left"),
+            0, len(buckets) - 1)
+        words, nbits = jax.lax.switch(idx, [_branch(b) for b in buckets],
+                                      jnp.int32(0))
     return words, nbits, s["trailing"], ns
 
 

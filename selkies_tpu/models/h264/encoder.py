@@ -86,6 +86,7 @@ from selkies_tpu.ops.colorspace import bgrx_to_i420, rgb_to_i420
 __all__ = ["TPUH264Encoder", "make_frame_step"]
 
 
+@jax.named_scope("enc.ingest")
 def _convert_pad(frame, *, pad_h: int, pad_w: int, channels: int):
     """Packed frame -> padded I420 planes (device)."""
     if channels == 4:
@@ -162,10 +163,11 @@ def _p_bits_step(y, u, v, qp, ref_y, ref_u, ref_v):
     overflow fallback (fetched on the rare nbits > cap frame)."""
     out = encode_frame_p_planes(y, u, v, ref_y, ref_u, ref_v, qp)
     words, nbits, trailing, _ns = pack_p_slice_bits_active(out, BITS_WORD_CAP)
-    nskip = out["skip"].sum().astype(jnp.int32)
-    meta = jnp.stack([nbits, trailing, nskip]).astype(jnp.uint32)
-    prefix = jnp.concatenate([meta, words[:BITS_PREFIX_WORDS]])
-    header, buf = pack_p_compact(out)
+    with jax.named_scope("enc.downlink"):
+        nskip = out["skip"].sum().astype(jnp.int32)
+        meta = jnp.stack([nbits, trailing, nskip]).astype(jnp.uint32)
+        prefix = jnp.concatenate([meta, words[:BITS_PREFIX_WORDS]])
+        header, buf = pack_p_compact(out)
     return prefix, words, header, buf, out["recon_y"], out["recon_u"], out["recon_v"]
 
 
@@ -185,18 +187,19 @@ def _p_toks_step(y, u, v, qp, ref_y, ref_u, ref_v):
     (native/cabac_pack.cc)."""
     out = encode_frame_p_planes(y, u, v, ref_y, ref_u, ref_v, qp)
     words, ntok, counts, ns = pack_p_slice_tokens_active(out, TOK_WORD_CAP)
-    skip = out["skip"].reshape(-1)
-    nskip = skip.sum().astype(jnp.int32)
-    skip_words = _bitpack32(skip)
-    m = counts.shape[0]
-    cnt16 = jnp.pad(counts.astype(jnp.int16), (0, m & 1))
-    cnt_words = jax.lax.bitcast_convert_type(
-        cnt16.reshape(-1, 2), jnp.int32).reshape(-1)
-    meta = jnp.stack([ntok, ns, nskip])
-    prefix = jnp.concatenate([
-        meta.astype(jnp.uint32), skip_words.astype(jnp.uint32),
-        cnt_words.astype(jnp.uint32), words[:TOK_PREFIX_WORDS]])
-    header, buf = pack_p_compact(out)
+    with jax.named_scope("enc.downlink"):
+        skip = out["skip"].reshape(-1)
+        nskip = skip.sum().astype(jnp.int32)
+        skip_words = _bitpack32(skip)
+        m = counts.shape[0]
+        cnt16 = jnp.pad(counts.astype(jnp.int16), (0, m & 1))
+        cnt_words = jax.lax.bitcast_convert_type(
+            cnt16.reshape(-1, 2), jnp.int32).reshape(-1)
+        meta = jnp.stack([ntok, ns, nskip])
+        prefix = jnp.concatenate([
+            meta.astype(jnp.uint32), skip_words.astype(jnp.uint32),
+            cnt_words.astype(jnp.uint32), words[:TOK_PREFIX_WORDS]])
+        header, buf = pack_p_compact(out)
     return prefix, words, header, buf, out["recon_y"], out["recon_u"], out["recon_v"]
 
 
@@ -208,23 +211,28 @@ def _p_toks_step(y, u, v, qp, ref_y, ref_u, ref_v):
 Y_CHUNKS = 4
 
 
+@jax.named_scope("enc.ingest")
+def _join_y(y0, y1, y2, y3):
+    return jnp.concatenate([y0, y1, y2, y3], 0)
+
+
 def _i_planes_step_chunked(y0, y1, y2, y3, u, v, qp):
-    y = jnp.concatenate([y0, y1, y2, y3], 0)
+    y = _join_y(y0, y1, y2, y3)
     return (*_i_planes_step(y, u, v, qp), y, u, v)
 
 
 def _p_bits_step_chunked(y0, y1, y2, y3, u, v, qp, ref_y, ref_u, ref_v):
-    y = jnp.concatenate([y0, y1, y2, y3], 0)
+    y = _join_y(y0, y1, y2, y3)
     return (*_p_bits_step(y, u, v, qp, ref_y, ref_u, ref_v), y, u, v)
 
 
 def _p_toks_step_chunked(y0, y1, y2, y3, u, v, qp, ref_y, ref_u, ref_v):
-    y = jnp.concatenate([y0, y1, y2, y3], 0)
+    y = _join_y(y0, y1, y2, y3)
     return (*_p_toks_step(y, u, v, qp, ref_y, ref_u, ref_v), y, u, v)
 
 
 def _p_planes_step_chunked(y0, y1, y2, y3, u, v, qp, ref_y, ref_u, ref_v):
-    y = jnp.concatenate([y0, y1, y2, y3], 0)
+    y = _join_y(y0, y1, y2, y3)
     return (*_p_planes_step(y, u, v, qp, ref_y, ref_u, ref_v), y, u, v)
 
 
@@ -236,6 +244,7 @@ def _p_planes_step_chunked(y0, y1, y2, y3, u, v, qp, ref_y, ref_u, ref_v):
 # host->device operations.
 
 
+@jax.named_scope("enc.ingest")
 def _unpack_delta(packed, w):
     """packed: [idx int32 LE bytes (k,4)] ++ yb ++ ub ++ vb, k inferred.
     w is the TILE width in luma columns (== plane width for full bands)."""
@@ -298,7 +307,8 @@ def _p_scatter_multi_step(packed_a, packed_b, qps, sy, su, sv, ref_y, ref_u, ref
     motion estimation references frame k-1's reconstruction, exactly as
     K single steps would. One execute + one prefix fetch instead of 2K
     host<->device operations."""
-    packed = jnp.concatenate([packed_a, packed_b], 0)
+    with jax.named_scope("enc.ingest"):
+        packed = jnp.concatenate([packed_a, packed_b], 0)
 
     def body(carry, xs):
         pk, qp = xs
@@ -361,6 +371,7 @@ def _unpack_delta2(packed, w, bucket, cbucket):
     return up_idx, pool_dst, pairs, yb, ub, vb
 
 
+@jax.named_scope("enc.ingest")
 def _apply_tiles2(sy, su, sv, py, pu, pv, packed, *, tile_w, bucket, cbucket):
     """Copy remaps (pool -> planes), then pixel uploads (-> planes AND
     their pool slots). Copies run first so a same-step upload can land
@@ -418,6 +429,7 @@ def _apply_tiles2(sy, su, sv, py, pu, pv, packed, *, tile_w, bucket, cbucket):
     return jax.lax.fori_loop(0, bucket, up_body, (sy, su, sv, py, pu, pv))
 
 
+@jax.named_scope("enc.ingest")
 def _pool_seed_step(pairs, sy, su, sv, py, pu, pv, *, tile_w, sbucket):
     """Seed pool slots by GATHERING tiles from the resident source
     planes — no pixel upload at all (only the (slot, idx) list crosses).
@@ -471,7 +483,8 @@ def _p_scatter_multi_step2(packed_a, packed_b, qps, sy, su, sv, py, pu, pv,
     rides in the carry, so frame k's copy remaps may reference slots
     frame k-1's uploads inserted — matching the host cache's sequential
     split() order exactly."""
-    packed = jnp.concatenate([packed_a, packed_b], 0)
+    with jax.named_scope("enc.ingest"):
+        packed = jnp.concatenate([packed_a, packed_b], 0)
 
     def body(carry, xs):
         pk, qp = xs
@@ -1229,25 +1242,29 @@ class TPUH264Encoder:
         parts += [u, v]
         self.link_bytes.add("up_full", sum(p.nbytes for p in parts))
         t0 = time.perf_counter()
-        out = list(self._upload_pool.map(jax.device_put, parts))
+        with tracer.span("h2d"):
+            out = list(self._upload_pool.map(jax.device_put, parts))
         self._t_h2d_ms += (time.perf_counter() - t0) * 1e3
         return out
 
     def _convert_timed(self, frame: np.ndarray):
         t0 = time.perf_counter()
-        planes = self._prep.convert(frame)
+        with tracer.span("convert"):
+            planes = self._prep.convert(frame)
         self._t_conv_ms += (time.perf_counter() - t0) * 1e3
         return planes
 
     def _convert_tiles_timed(self, frame: np.ndarray, idx, tile_w: int):
         t0 = time.perf_counter()
-        out = self._prep.convert_tiles(frame, idx, tile_w)
+        with tracer.span("convert"):
+            out = self._prep.convert_tiles(frame, idx, tile_w)
         self._t_conv_ms += (time.perf_counter() - t0) * 1e3
         return out
 
     def _put_timed(self, arr):
         t0 = time.perf_counter()
-        out = jax.device_put(arr)
+        with tracer.span("h2d"):
+            out = jax.device_put(arr)
         self._t_h2d_ms += (time.perf_counter() - t0) * 1e3
         return out
 
@@ -1755,7 +1772,8 @@ class TPUH264Encoder:
             link_bytes=self.link_bytes, prefix_bytes=fused.nbytes,
             note_need=self._note_need,
             ltr_ref=rec.ltr_ref, mark_ltr=rec.mark_ltr,
-            mmco_evict=rec.mmco_evict, entropy_coder=self._coder)
+            mmco_evict=rec.mmco_evict, entropy_coder=self._coder,
+            pts=rec.meta)
         return au, skipped, t1, tu, time.perf_counter(), mode
 
     def _complete_batch(self, recs, pfx_slice_d, pfx_rows_d, denses_d, bufs_d):
@@ -1824,7 +1842,7 @@ class TPUH264Encoder:
             fi.check("frontend")
         # classify on every frame (advances the previous-frame state even
         # across IDRs) but only short-circuit on P frames
-        with tracer.span("classify"):
+        with tracer.span("classify", pts=meta):
             kind, dirty_idx = self._classify(frame, damage)
         classify_ms = (time.perf_counter() - t0) * 1e3
         if telemetry.enabled:
@@ -2145,6 +2163,7 @@ class TPUH264Encoder:
                     rec.future.result()[rec.batch_slot])
             else:
                 au, skipped, t1, tu, t2, mode, step_ms, fetch_ms = rec.future.result()
+            handoff_ms = (time.perf_counter() - t2) * 1e3
         except Exception:
             self._ref = None
             self._src = None
@@ -2166,6 +2185,7 @@ class TPUH264Encoder:
             classify_ms=rec.classify_ms, convert_ms=rec.convert_ms,
             h2d_ms=rec.h2d_ms,
             downlink_mode=mode,
+            handoff_wait_ms=handoff_ms,
             upload_kind="delta" if rec.kind == "pd" else "full",
             dirty_frac=(min(1.0, dirty / self._ntiles)
                         if rec.kind == "pd" else 1.0),
@@ -2180,7 +2200,7 @@ class TPUH264Encoder:
         return (step_ms, t_ready). Worker-side only — the main thread
         never waits — so the upload/step/fetch attribution costs one
         block_until_ready per frame, not a pipeline stall."""
-        with tracer.span("step"):
+        with tracer.span("step", pts=rec.meta):
             jax.block_until_ready(handle)
         t_ready = time.perf_counter()
         t_disp = rec.t_disp or rec.t0
@@ -2197,7 +2217,7 @@ class TPUH264Encoder:
             return self._complete_bits(rec)
         if rec.kind == "pd":
             step_ms, t_ready = self._wait_step(rec, rec.pfx_slice_d)
-            with tracer.span("fetch"):
+            with tracer.span("fetch", pts=rec.meta):
                 fused = np.asarray(rec.pfx_slice_d)
             fetch_ms = (time.perf_counter() - t_ready) * 1e3
             out = self._complete_sparse_p(fused, rec.prefix_d, rec.hdr_d,
@@ -2207,7 +2227,8 @@ class TPUH264Encoder:
         hdr_words = self._hdr_words_i if rec.kind == "i" else self._hdr_words_p
         cap = CAP_ROWS
         step_ms, t_ready = self._wait_step(rec, rec.prefix_d)
-        prefix = np.asarray(rec.prefix_d)
+        with tracer.span("fetch", pts=rec.meta):
+            prefix = np.asarray(rec.prefix_d)
         fetch_ms = (time.perf_counter() - t_ready) * 1e3
         self.link_bytes.add("down_prefix", prefix.nbytes)
         header, data, n = split_prefix(prefix, hdr_words)
@@ -2218,12 +2239,12 @@ class TPUH264Encoder:
         t1 = time.perf_counter()
         skipped = 0
         if rec.kind == "i":
-            with tracer.span("unpack"):
+            with tracer.span("unpack", pts=rec.meta):
                 fc = unpack_i_compact(header, data, rec.qp)
             tu = time.perf_counter()
             # frame_num counts from the last IDR (7.4.3: gaps are
             # disallowed by our SPS)
-            with tracer.span("pack"):
+            with tracer.span("pack", pts=rec.meta):
                 if self._coder == "cabac":
                     slice_nal = pack_slice_cabac(
                         fc, self.params, frame_num=0, idr=True,
@@ -2234,11 +2255,11 @@ class TPUH264Encoder:
                         idr_pic_id=rec.idr_pic_id)
             au = self._headers + slice_nal
         else:
-            with tracer.span("unpack"):
+            with tracer.span("unpack", pts=rec.meta):
                 pfc = unpack_p_compact(header, data, rec.qp)
             tu = time.perf_counter()
             skipped = int(pfc.skip.sum())
-            with tracer.span("pack"):
+            with tracer.span("pack", pts=rec.meta):
                 if self._coder == "cabac":
                     au = pack_slice_p_cabac(
                         pfc, self.params, rec.frame_num,
@@ -2258,7 +2279,8 @@ class TPUH264Encoder:
         """Device-entropy P frame: fetch [meta ++ bit words], splice the
         slice header, done — no coefficient unpack, no host CAVLC."""
         step_ms, t_ready = self._wait_step(rec, rec.prefix_d)
-        arr = np.asarray(rec.prefix_d)  # uint32: nbits, trailing, nskip, words...
+        with tracer.span("fetch", pts=rec.meta):
+            arr = np.asarray(rec.prefix_d)  # uint32: nbits, trailing, nskip, words...
         fetch_ms = (time.perf_counter() - t_ready) * 1e3
         self.link_bytes.add("down_bits", arr.nbytes)
         nbits, trailing, skipped = int(arr[0]), int(arr[1]), int(arr[2])
@@ -2278,14 +2300,16 @@ class TPUH264Encoder:
         need = (nbits + 31) // 32
         words = arr[3 : 3 + min(need, BITS_PREFIX_WORDS)]
         if need > BITS_PREFIX_WORDS:  # spill: one extra fetch
-            with tracer.span("bits_fetch"):
+            with tracer.span("bits_fetch", pts=rec.meta):
                 rest = _fetch_rest(rec.words_d, need, BITS_PREFIX_WORDS)
             self.link_bytes.add("down_bits_spill", rest.nbytes)
             words = np.concatenate([words, rest])
         t1 = time.perf_counter()
-        au = assemble_p_nal(words, nbits, trailing, self.params, rec.frame_num,
-                            rec.qp, ltr_ref=rec.ltr_ref, mark_ltr=rec.mark_ltr,
-                            mmco_evict=rec.mmco_evict)
+        with tracer.span("pack", pts=rec.meta):
+            au = assemble_p_nal(words, nbits, trailing, self.params,
+                                rec.frame_num, rec.qp, ltr_ref=rec.ltr_ref,
+                                mark_ltr=rec.mark_ltr,
+                                mmco_evict=rec.mmco_evict)
         return au, skipped, t1, t1, time.perf_counter(), "bits", step_ms, fetch_ms
 
     def _complete_toks(self, rec: "_Pending"):
@@ -2294,7 +2318,8 @@ class TPUH264Encoder:
         host arithmetic engine — no coefficient unpack, no host
         binarization."""
         step_ms, t_ready = self._wait_step(rec, rec.prefix_d)
-        arr = np.asarray(rec.prefix_d)  # uint32: ntok, ns, nskip, ...
+        with tracer.span("fetch", pts=rec.meta):
+            arr = np.asarray(rec.prefix_d)  # uint32: ntok, ns, nskip, ...
         fetch_ms = (time.perf_counter() - t_ready) * 1e3
         self.link_bytes.add("down_bits", arr.nbytes)
         ntok, ns, skipped = int(arr[0]), int(arr[1]), int(arr[2])
@@ -2326,15 +2351,17 @@ class TPUH264Encoder:
         need = (ntok + 1) // 2
         words = arr[base:base + min(need, TOK_PREFIX_WORDS)]
         if need > TOK_PREFIX_WORDS:  # spill: one extra fetch
-            with tracer.span("bits_fetch"):
+            with tracer.span("bits_fetch", pts=rec.meta):
                 rest = _fetch_rest(rec.words_d, need, TOK_PREFIX_WORDS)
             self.link_bytes.add("down_bits_spill", rest.nbytes)
             words = np.concatenate([words, rest])
         t1 = time.perf_counter()
-        au = assemble_p_cabac_nal(words, ntok, counts, skip, self.params,
-                                  rec.frame_num, rec.qp, ltr_ref=rec.ltr_ref,
-                                  mark_ltr=rec.mark_ltr,
-                                  mmco_evict=rec.mmco_evict)
+        with tracer.span("pack", pts=rec.meta):
+            au = assemble_p_cabac_nal(words, ntok, counts, skip, self.params,
+                                      rec.frame_num, rec.qp,
+                                      ltr_ref=rec.ltr_ref,
+                                      mark_ltr=rec.mark_ltr,
+                                      mmco_evict=rec.mmco_evict)
         return (au, skipped, t1, t1, time.perf_counter(), "cabac", step_ms,
                 fetch_ms)
 

@@ -235,6 +235,7 @@ def _encode_row0(y_row, u_row, v_row, qp, qp_c):
 
 
 @jax.jit
+@jax.named_scope("enc.intra")
 def encode_frame_planes(y, u, v, qp):
     """Jitted all-Intra16x16 frame encode on padded planes.
 
@@ -525,8 +526,8 @@ def hier_me_mc(cur, ref_y, ry_pad, ru_pad, rv_pad, dy_max: int | None = None,
     Element-exact vs numpy_ref.hier_search_me + mc_luma/mc_chroma: the
     chroma bilinear runs on the globally-shifted plane with the same
     frac weights, so selected values match the per-MB gather formulation.
-    (Why no gathers: tools/profile_slope2.py measured 30 ms per full-plane
-    gather on v5e vs 0.26 ms per global-shift SAD map.)
+    (Why no gathers: a full-plane gather measured 30 ms on v5e vs
+    0.26 ms per global-shift SAD map, on an earlier remote-chip setup.)
 
     ``coarse`` (a (TOPK, 2) candidate array) overrides the internal
     coarse vote — the 2D tile grid passes the row-merged selection
@@ -544,12 +545,12 @@ def hier_me_mc(cur, ref_y, ry_pad, ru_pad, rv_pad, dy_max: int | None = None,
     ranks = jnp.arange(ncand, dtype=jnp.int32)
     scale = 1 << int(np.int64(ncand - 1)).bit_length()
     # statically unrolled chunks. NOT a vmap: batched dynamic_slice
-    # lowers to a gather (~30 ms per full plane on v5e,
-    # tools/profile_slope2.py); the unrolled Python loop keeps every
-    # shift a cheap DynamicSlice. Measured at 1080p/ncand=76 on an
-    # earlier remote-chip setup: chunk=4 ~= chunk=19 ~= unchunked within
-    # its noise floor (the arithmetic, not step launches, bounds this
-    # scan) — 4 is kept for its smaller compiled body.
+    # lowers to a gather (~30 ms per full plane on v5e); the unrolled
+    # Python loop keeps every shift a cheap DynamicSlice. Measured at
+    # 1080p/ncand=76 on an earlier remote-chip setup: chunk=4 ~= chunk=19
+    # ~= unchunked within its noise floor (the arithmetic, not step
+    # launches, bounds this scan) — 4 is kept for its smaller compiled
+    # body.
     chunk = next(c for c in (4, 19, 13, 11, 7, 5, 3, 2, 1) if ncand % c == 0)
     cands_c = cands.reshape(-1, chunk, 2)
     ranks_c = ranks.reshape(-1, chunk)
@@ -723,9 +724,10 @@ def encode_frame_p_planes(y, u, v, ref_y, ref_u, ref_v, qp, search: int = 8, me:
     v = v.astype(jnp.int32)
     qp = jnp.asarray(qp, jnp.int32)
 
-    ry = jnp.pad(ref_y, MV_PAD, mode="edge")
-    ru = jnp.pad(ref_u, MV_PAD, mode="edge")
-    rv = jnp.pad(ref_v, MV_PAD, mode="edge")
+    with jax.named_scope("enc.me"):
+        ry = jnp.pad(ref_y, MV_PAD, mode="edge")
+        ru = jnp.pad(ref_u, MV_PAD, mode="edge")
+        rv = jnp.pad(ref_v, MV_PAD, mode="edge")
     mvs, pred_y, pred_u, pred_v = _me_mc_dispatch(
         y, ref_y, ry, ru, rv, search=search, me=me)
     return _p_transform_tail(y, u, v, qp, mvs, pred_y, pred_u, pred_v)
@@ -814,9 +816,10 @@ def encode_tile_p_planes(y, u, v, slab_y, slab_u, slab_v, qp, halo: int,
     halo_c, halo_cc = halo // 2, halo_cols // 2
     vt, vtc = MV_PAD - halo, MV_PAD - halo_c
     ht, htc = MV_PAD - halo_cols, MV_PAD - halo_cc
-    ry = jnp.pad(slab_y, ((vt, vt), (ht, ht)), mode="edge")
-    ru = jnp.pad(slab_u, ((vtc, vtc), (htc, htc)), mode="edge")
-    rv = jnp.pad(slab_v, ((vtc, vtc), (htc, htc)), mode="edge")
+    with jax.named_scope("enc.me"):
+        ry = jnp.pad(slab_y, ((vt, vt), (ht, ht)), mode="edge")
+        ru = jnp.pad(slab_u, ((vtc, vtc), (htc, htc)), mode="edge")
+        rv = jnp.pad(slab_v, ((vtc, vtc), (htc, htc)), mode="edge")
     # tile-local reference (coarse candidate voting sees the tile when no
     # merged `coarse` list is injected)
     ref_y = slab_y[halo : slab_y.shape[0] - halo] if halo else slab_y
@@ -837,6 +840,7 @@ def encode_tile_p_planes(y, u, v, slab_y, slab_u, slab_v, qp, halo: int,
                              defer_skip=defer_skip)
 
 
+@jax.named_scope("enc.me")
 def _me_mc_dispatch(y, ref_y, ry, ru, rv, *, search: int, me: str,
                     dy_max: int | None = None, dx_max: int | None = None,
                     coarse=None):
@@ -860,6 +864,7 @@ def _me_mc_dispatch(y, ref_y, ry, ru, rv, *, search: int, me: str,
     return mvs, mc_luma(ry, mvs), mc_chroma(ru, mvs), mc_chroma(rv, mvs)
 
 
+@jax.named_scope("enc.tq")
 def _p_transform_tail(y, u, v, qp, mvs, pred_y, pred_u, pred_v,
                       defer_skip: bool = False):
     """Transform + quant + recon + skip derivation — everything after
@@ -980,6 +985,7 @@ def _p_components(out):
     return n, mbh, mbw, mv_words.reshape(-1).astype(jnp.int32), _bitmap_words(flags), buf
 
 
+@jax.named_scope("enc.downlink")
 def pack_p_compact(out):
     """P-frame outputs -> (header int32, data int16 (M*26, 16)).
 
@@ -995,6 +1001,7 @@ def pack_p_compact(out):
     return header, buf
 
 
+@jax.named_scope("enc.downlink")
 def pack_p_sparse_var(out, nscap: int, cap_rows: int):
     """Skip-aware variable-density P downlink (the delta-upload path):
     ONE int16 buffer whose live content is proportional to frame
@@ -1053,6 +1060,7 @@ def pack_p_sparse_var(out, nscap: int, cap_rows: int):
     return fused, dense, buf
 
 
+@jax.named_scope("enc.downlink")
 def pack_p_sparse_packed(out, nscap: int, cap_rows: int, density_pct: int = 75):
     """Bit-packed variant of pack_p_sparse_var: coefficient rows ride as
     a significance bitmap + their nonzero values only.
@@ -1146,6 +1154,7 @@ def pack_p_sparse_packed(out, nscap: int, cap_rows: int, density_pct: int = 75):
     return fused, dense, buf
 
 
+@jax.named_scope("enc.downlink")
 def pack_p_sparse_entropy(out, nscap: int, cap_rows: int,
                           density_pct: int | None, bits_words: int,
                           min_mbs: int, buckets: tuple[int, ...],
@@ -1272,6 +1281,7 @@ def _pack_p_sparse_cabac(out, nscap: int, cap_rows: int,
     return fused2, dense, buf
 
 
+@jax.named_scope("enc.downlink")
 def fuse_downlink(header, buf, cap_rows: int):
     """Fuse header + the first cap_rows data rows into ONE int16 buffer.
 
@@ -1286,6 +1296,7 @@ def fuse_downlink(header, buf, cap_rows: int):
     return prefix
 
 
+@jax.named_scope("enc.downlink")
 def pack_i_compact(out):
     """IDR outputs -> (header int32, data int16 (M*27, 16)).
 
@@ -1313,6 +1324,7 @@ def pack_i_compact(out):
 # ---------------------------------------------------------------------------
 # Delta upload: dirty-band scatter into device-resident source planes
 # ---------------------------------------------------------------------------
+@jax.named_scope("enc.ingest")
 def scatter_tiles(y, u, v, yb, ub, vb, idx, tile_w: int):
     """Scatter uploaded I420 TILES into device-resident planes.
 

@@ -469,8 +469,8 @@ def coarse_vote_candidates(y: np.ndarray, ref_y: np.ndarray) -> np.ndarray:
     few global displacements (scroll/pan/drag), which is what makes a
     frame-level candidate set competitive with per-MB search at a fraction
     of the cost — and it keeps the device path free of gathers, which are
-    pathologically slow on TPU (tools/profile_slope2.py: 30 ms per
-    full-plane gather vs 0.26 ms per global-shift SAD map).
+    pathologically slow on TPU (30 ms per full-plane gather vs 0.26 ms
+    per global-shift SAD map, measured on an earlier remote v5e setup).
     """
     h, w = y.shape
     mbh, mbw = h // 16, w // 16

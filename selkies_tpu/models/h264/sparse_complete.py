@@ -63,7 +63,7 @@ __all__ = ["complete_sparse_slice", "fetch_rest"]
 
 
 def _settle_device_bits(fused, need, note_need, link_bytes, prefix_bytes,
-                        full_d):
+                        full_d, pts=None):
     """Shared mode=1 completion plumbing — hint feedback, downlink-byte
     accounting and the hint-too-small refetch are identical for both
     entropy coders; only the payload parse after this differs. Returns
@@ -75,7 +75,7 @@ def _settle_device_bits(fused, need, note_need, link_bytes, prefix_bytes,
     if need > len(fused):  # hint too small: refetch
         # span marks only the EXTRA transfer (tracing.py contract —
         # the main prefix fetch rode the caller's "fetch" span)
-        with tracer.span("bits_fetch"):
+        with tracer.span("bits_fetch", pts=pts):
             fused = np.asarray(full_d)
         if link_bytes is not None:
             link_bytes.add("down_bits_refetch", fused.nbytes)
@@ -120,6 +120,7 @@ def complete_sparse_slice(
     mmco_evict: tuple = (),
     entropy_coder: str = "cavlc",
     cabac_init_idc: int = 0,
+    pts=None,
 ) -> tuple[bytes, int, float, str]:
     """One P slice's fused sparse downlink → (nal, skipped_mbs,
     t_unpacked, downlink_mode).
@@ -130,6 +131,7 @@ def complete_sparse_slice(
     fallback (callers whose nscap equals the slice MB count pass None —
     that branch is structurally unreachable for them). ``t_unpacked`` is
     the unpack→pack boundary timestamp for the caller's stage split.
+    ``pts`` tags the tracer spans with the frame's 90 kHz timestamp.
 
     ``prefix_bytes`` is the caller's already-fetched prefix size: the
     accounting lives here (not at the fetch site) because only the meta
@@ -154,7 +156,8 @@ def complete_sparse_slice(
             base = ENTROPY_META16 + 2 * sw
             need = base + ns + 2 * nw
             fused = _settle_device_bits(fused, need, note_need,
-                                        link_bytes, prefix_bytes, full_d)
+                                        link_bytes, prefix_bytes, full_d,
+                                        pts)
             skip_words = (np.ascontiguousarray(
                 fused[ENTROPY_META16:base]).view(np.int32)
                 .astype(np.int64) & 0xFFFFFFFF)
@@ -164,7 +167,7 @@ def complete_sparse_slice(
             words = np.ascontiguousarray(
                 fused[base + ns:base + ns + 2 * nw]).view(np.uint32)
             t_unpacked = time.perf_counter()
-            with tracer.span("pack"):
+            with tracer.span("pack", pts=pts):
                 nal = assemble_p_cabac_nal(
                     words, ntok, counts, skip, params, frame_num, qp,
                     ltr_ref=ltr_ref, mark_ltr=mark_ltr,
@@ -177,11 +180,12 @@ def complete_sparse_slice(
             nw = (nbits + 31) // 32
             need = ENTROPY_META16 + 2 * nw
             fused = _settle_device_bits(fused, need, note_need,
-                                        link_bytes, prefix_bytes, full_d)
+                                        link_bytes, prefix_bytes, full_d,
+                                        pts)
             words = np.ascontiguousarray(
                 fused[ENTROPY_META16:ENTROPY_META16 + 2 * nw]).view(np.uint32)
             t_unpacked = time.perf_counter()
-            with tracer.span("pack"):
+            with tracer.span("pack", pts=pts):
                 nal = assemble_p_nal(
                     words, nbits, trailing, params, frame_num, qp,
                     ltr_ref=ltr_ref, mark_ltr=mark_ltr,
@@ -193,7 +197,7 @@ def complete_sparse_slice(
     if link_bytes is not None and prefix_bytes:
         link_bytes.add("down_prefix", prefix_bytes)
     downlink_mode = "coeff"
-    with tracer.span("unpack"):
+    with tracer.span("unpack", pts=pts):
         need_fn = p_sparse_packed_need if packed else p_sparse_var_need
         need, n, ns = need_fn(fused, mbh, mbw, nscap, cap_rows)
         if note_need is not None:
@@ -226,7 +230,7 @@ def complete_sparse_slice(
                 pfc = unpack_p_compact(dense, rows, qp)
                 downlink_mode = "dense"
     t_unpacked = time.perf_counter()
-    with tracer.span("pack"):
+    with tracer.span("pack", pts=pts):
         if wire is not None:
             nal = pack_slice_p_sparse_native(
                 wire, params, frame_num, qp, ltr_ref=ltr_ref,

@@ -94,3 +94,8 @@ class FrameStats:
     # (static all-skip) or encoder rows that don't attribute it. A
     # banded frame reports "bits" only when EVERY band shipped bits.
     downlink_mode: str = ""
+    # how long the finished AU waited between its completion worker
+    # returning it and submit()/flush() handing it out (the encoder
+    # emits in submission order, so a finished frame waits for the next
+    # call to reach it); 0 for static frames, which complete inline
+    handoff_wait_ms: float = 0.0

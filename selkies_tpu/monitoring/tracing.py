@@ -21,6 +21,15 @@ Enable with SELKIES_TRACING=1 (or tracer.enable()); the ring holds the
 most recent `capacity` spans (default 8192 ≈ 2-3 s of a busy 1080p60
 pipeline across ~5 stages).
 
+While enabled, every span is also a `jax.profiler.TraceAnnotation`
+named ``selkies.<name>``: under a `jax.profiler` trace the program's
+spans land on the same clock as the device ops, so an idle gap on the
+device can be attributed to the host stage that covers it. Spans that
+know their frame take its 90 kHz pts (`tracer.span("pack", pts=ts)`),
+recorded as the annotation's ``pts`` stat: the spans of one frame share
+that identifier across threads. A disabled tracer records nothing in
+either place.
+
 Span-name vocabulary (the full set emitted by the framework — keep this
 list authoritative when adding instrumentation so dashboards and the
 black-box bundles stay greppable):
@@ -33,10 +42,14 @@ black-box bundles stay greppable):
                   hints) incl. the tile-cache hash/split
                   (models/h264/encoder.py). The matching
                   selkies_stage_ms stage is "classify"; its front-end
-                  siblings "convert" (BGRx→I420 of the upload payload)
-                  and "h2d" (host→device transfer enqueues) are emitted
-                  per frame at frame_done — together they decompose
+                  siblings "convert" and "h2d" below are emitted per
+                  frame at frame_done — together they decompose
                   FrameStats.upload_ms, the host front-end cost
+    convert       BGRx→I420 of the upload payload (full planes or the
+                  dirty tiles), FrameStats.convert_ms
+                  (models/h264/encoder.py)
+    h2d           host→device transfer enqueues of the upload,
+                  FrameStats.h2d_ms (models/h264/encoder.py)
     submit        pipelined encoder dispatch (classify + upload + step)
     encode        synchronous encode_frame path (non-pipelined rows)
     send          sink callback (transport handoff) per access unit
@@ -119,6 +132,31 @@ black-box bundles stay greppable):
   audio (audio/pipeline.py):
     audio-encode  one 10 ms Opus frame
     audio-send    audio sink callback
+
+The solo video path's submit, classify, step, fetch, unpack, pack,
+bits_fetch, send and ws-send spans carry the frame's ``pts``.
+
+Device scopes (`jax.named_scope`, models/h264/): inside the jitted steps
+every op carries the innermost of these names in its op-name path, so a
+device trace splits one step executable by stage. The vocabulary is
+flat and fixed; the scopes sit in the shared stage functions, so every
+step built on them (full-P, IDR, delta scatter, grouped scan, CABAC
+tokens, banded) is covered.
+
+    enc.ingest             plane join of the chunked uploads, packed-frame
+                           convert/pad, delta/tile scatter and pool remap
+    enc.intra              IDR prediction, transform and quantization
+                           (encode_frame_planes)
+    enc.me                 reference padding + ME/MC (Pallas or XLA)
+    enc.tq                 P residual, transform, quant, recon, skip
+                           decision (_p_transform_tail)
+    enc.entropy.structure  device CAVLC/CABAC per-MB syntax structure
+    enc.entropy.compact    coded-MB compaction inside each bucket branch
+    enc.entropy.emit       VLC/token emission, bit packing and merge; the
+                           bucket switch itself
+    enc.downlink           compact/sparse downlink packing, the fused
+                           prefix and meta assembly, the dense fallback
+                           header/buf
 """
 
 from __future__ import annotations
@@ -133,21 +171,33 @@ from collections import deque
 __all__ = ["Tracer", "tracer", "span"]
 
 
+def _annotation(name: str, pts):
+    """The span's profiler twin: a no-op outside a jax.profiler trace."""
+    from jax.profiler import TraceAnnotation
+
+    if pts is None:
+        return TraceAnnotation("selkies." + name)
+    return TraceAnnotation("selkies." + name, pts=pts)
+
+
 class _Span:
     """Context manager recording one stage execution."""
 
-    __slots__ = ("t", "name", "t0")
+    __slots__ = ("t", "name", "t0", "ann")
 
-    def __init__(self, t: "Tracer", name: str):
+    def __init__(self, t: "Tracer", name: str, pts=None):
         self.t = t
         self.name = name
+        self.ann = _annotation(name, pts)
 
     def __enter__(self):
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.t._record(self.name, self.t0, time.perf_counter())
+        self.ann.__exit__(*exc)
         return False
 
 
@@ -189,11 +239,12 @@ class Tracer:
 
     # -- recording -----------------------------------------------------
 
-    def span(self, name: str):
-        """`with tracer.span("encode"):` — no-op object when disabled."""
+    def span(self, name: str, pts=None):
+        """`with tracer.span("encode"):` — no-op object when disabled.
+        ``pts``: the frame's 90 kHz timestamp, when the span knows it."""
         if not self.enabled:
             return _NOOP
-        return _Span(self, name)
+        return _Span(self, name, pts)
 
     def instant(self, name: str) -> None:
         """Zero-duration marker (frame drops, forced IDRs, reconnects)."""
@@ -264,6 +315,6 @@ class Tracer:
 tracer = Tracer()
 
 
-def span(name: str):
+def span(name: str, pts=None):
     """Module-level convenience: `with tracing.span("pack"):`."""
-    return tracer.span(name)
+    return tracer.span(name, pts)

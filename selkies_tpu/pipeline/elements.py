@@ -488,7 +488,7 @@ class VideoPipeline:
                     # telemetry.span also sets the frame ContextVar, which
                     # asyncio.to_thread copies — the encoder's tile-cache
                     # events correlate without API changes
-                    with tracer.span("submit"), \
+                    with tracer.span("submit", pts=ts), \
                             telemetry.span("submit", fid, session=self.session):
                         if getattr(self.encoder, "accepts_damage", False):
                             # capture-layer damage hints (XDamage /
@@ -694,7 +694,7 @@ class VideoPipeline:
             while self._outbox:
                 ef = self._outbox.popleft()
                 try:
-                    with tracer.span("send"), \
+                    with tracer.span("send", pts=ef.timestamp_90k), \
                             telemetry.span("send", ef.frame_id,
                                            session=self.session,
                                            bytes=len(ef.au)):

@@ -130,7 +130,7 @@ class WebSocketTransport:
             # frame's capture/encode events (congestion.on_frame_ack)
             telemetry.map_seq(self.session, seq, getattr(ef, "frame_id", 0))
             t0 = time.perf_counter()
-        with tracer.span("ws-send"):
+        with tracer.span("ws-send", pts=ef.timestamp_90k):
             ok = await self._send_binary(
                 pack_media_frame(KIND_VIDEO, flags, ef.timestamp_90k, ef.au, seq))
         if tele:
