@@ -23,7 +23,10 @@ Two entry points share one implementation:
   ``lax.switch`` — one executable, no recompiles (the same discipline
   as the NSCAP dense fallback in encoder_core.pack_p_sparse_packed).
   Compaction preserves raster order and padded slots emit zero bits,
-  so the merged stream is bit-identical to the full-grid coder.
+  so the merged stream is bit-identical to the full-grid coder. At the
+  ladder's top, each MB's 27 segments are first concatenated and whole
+  MBs merged, where every MB's bit bound allows: the merge's cost is per
+  unit, and a busy frame at a high QP codes every MB in few bits.
 
 Everything vectorizes: VLC tables become constant-array gathers; the
 per-level suffix-length adaptation and run_before chains are unrolled
@@ -716,6 +719,9 @@ def _frame_structure(out):
         "ch_blocks": ch_blocks, "nc_ch": nc_ch, "ch_emit": ch_emit,
         "coded": emit_mb, "trailing": trailing,
         "ns": coded_flat.sum().astype(jnp.int32),
+        # emitted luma 4x4 / chroma AC blocks that hold a coefficient
+        "coef_luma": (luma_tc_grid > 0).sum().astype(jnp.int32),
+        "coef_chroma": (ch_tc_grid > 0).sum().astype(jnp.int32),
         # full-grid context grids, consumed by the CABAC emitter
         # (device_cabac.py) for its neighbour ctx derivation — dead (and
         # DCE'd by the jit) on the CAVLC path
@@ -753,13 +759,77 @@ def _compact_structure(s, A: int, keys=_COMPACT_KEYS):
     return {k: cp(s[k]) for k in keys}
 
 
+def _level_bits_bound(a, small):
+    """Upper bound on the CAVLC bits of a level of magnitude `a` (0 for
+    a == 0), whatever the suffixLength (9.2.2.1): 2a for a <= 3 in a
+    block whose levels are all <= 3 (suffixLength stays <= 1 there),
+    else 7 / 14 / 19 bits up to 3 / 7 / 15, and 64 beyond (escape or
+    extended prefix)."""
+    big = jnp.where(a <= 3, 7, jnp.where(a <= 7, 14, jnp.where(a <= 15, 19, 64)))
+    return jnp.where(a == 0, 0, jnp.where(small, 2 * a, big))
+
+
+def _blocks_bits_bound(blocks, emit, chroma_dc: bool = False):
+    """Upper bound on each (..., L) residual block's CAVLC bits, from its
+    coefficients alone (no VLC): coeff_token <= 16 (8 for chroma DC),
+    each level by _level_bits_bound, total_zeros <= 9 (3), and the
+    run_befores <= 3 bits each plus the zeros they skip; an emitted
+    block with no coefficient writes a coeff_token of <= 6 bits."""
+    a = jnp.abs(blocks)
+    t = (a > 0).sum(-1)
+    lv = _level_bits_bound(a, (a.max(-1) <= 3)[..., None]).sum(-1)
+    if chroma_dc:
+        b = 8 + lv + 3 + 6
+    else:
+        b = jnp.where(t > 0, 16 + lv + 9 + 3 * jnp.maximum(t - 1, 0) + 16, 6)
+    return jnp.where(emit, b, 0)
+
+
+@jax.named_scope("enc.entropy.structure")
+def _mb_bits_bound(s):
+    """The largest per-MB bound on its slice-data bits in a structure:
+    the MB header exactly, its blocks by _blocks_bits_bound."""
+    return (s["hdr_bits"].sum(-1)
+            + _blocks_bits_bound(s["luma_blocks"], s["luma_emit"]).sum(-1)
+            + _blocks_bits_bound(s["cdc_blocks"], s["cdc_emit"], True).sum(-1)
+            + _blocks_bits_bound(s["ch_blocks"], s["ch_emit"]).sum(-1)).max()
+
+
+def _concat_rows(words, nbits, nwords: int):
+    """Concatenate each row's K bit buffers: (U, K, W) words + (U, K)
+    lengths -> ((U, nwords) words, (U,) lengths). Dense shifts only
+    (each segment to its bit phase, then to its word offset in log2
+    steps), no gather or scatter. Bits past nwords * 32 are LOST: the
+    caller bounds every row's length."""
+    U, K, W = words.shape
+    starts = jnp.cumsum(nbits, 1) - nbits
+    sh = (starts & 31)[..., None].astype(jnp.uint32)
+    hi = jnp.where(sh > 0, words >> sh, words)
+    lo = jnp.where(sh > 0, words << jnp.clip(32 - sh, 1, 31), jnp.uint32(0))
+    zero = jnp.zeros((U, K, 1), jnp.uint32)
+    x = (jnp.concatenate([hi, zero], -1) + jnp.concatenate([zero, lo], -1))
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, max(0, nwords - W - 1))))[..., :nwords]
+    q = starts >> 5
+    step = 1
+    while step < nwords:
+        moved = jnp.pad(x, ((0, 0), (0, 0), (step, 0)))[..., :nwords]
+        x = jnp.where(((q & step) > 0)[..., None], moved, x)
+        step *= 2
+    return x.sum(1, dtype=jnp.uint32), nbits.sum(1)
+
+
 @jax.named_scope("enc.entropy.emit")
-def _emit_slice_bits(s, word_cap: int):
+def _emit_slice_bits(s, word_cap: int, mb_fits=None):
     """The EXPENSIVE half: VLC-encode every block of a (possibly
     compacted) per-MB structure, pack each segment's codewords into bit
     buffers, and merge them into one stream. Cost scales with the
     structure's leading axis (U MBs), which is what makes the bucket
-    compaction activity-proportional. Returns (words, nbits)."""
+    compaction activity-proportional. Returns (words, nbits).
+
+    Given ``mb_fits`` (traced: every MB's bound, _mb_bits_bound, fits
+    MB_WORDS), each MB's 27 segments are first concatenated densely into
+    MB_WORDS words where it holds, so the merge joins U MBs instead of
+    U * 27 segments; the VLC and packing above are shared."""
     U = s["hdr_bits"].shape[0]
     lv, lb, _ = _encode_blocks(
         s["luma_blocks"].reshape(U * 16, 16), s["nc_luma"].reshape(-1),
@@ -798,7 +868,14 @@ def _emit_slice_bits(s, word_cap: int):
          cac_n.reshape(U, 8)],
         axis=1,
     ).reshape(U * 27)
-    return _merge_streams(seg_words, seg_bits, word_cap)
+    if mb_fits is None:
+        return _merge_streams(seg_words, seg_bits, word_cap)
+    return jax.lax.cond(
+        mb_fits,
+        lambda: _merge_streams(*_concat_rows(
+            seg_words.reshape(U, 27, BW), seg_bits.reshape(U, 27), MB_WORDS),
+            word_cap),
+        lambda: _merge_streams(seg_words, seg_bits, word_cap))
 
 
 def pack_p_slice_bits(out, word_cap: int = WORD_CAP_DEFAULT):
@@ -828,6 +905,11 @@ def bits_buckets(m: int, ladder=(256, 1024, 4096)) -> tuple[int, ...]:
     return tuple(sorted({min(int(b), m) for b in ladder} | {m}))
 
 
+# The top bucket merges whole MBs of up to this many words (2048 bits)
+# when every MB's bound (_mb_bits_bound) fits.
+MB_WORDS = 64
+
+
 def pack_p_slice_bits_active(out, word_cap: int = WORD_CAP_DEFAULT,
                              buckets: tuple[int, ...] | None = None):
     """Activity-proportional device CAVLC: like pack_p_slice_bits, but
@@ -836,11 +918,17 @@ def pack_p_slice_bits_active(out, word_cap: int = WORD_CAP_DEFAULT,
     The bucket (smallest entry >= the frame's coded-MB count ns) is
     selected ON DEVICE with lax.switch — all buckets compile into the
     one executable, each frame executes only its own, so a typing frame
-    pays the 256-slot coder while a scene cut pays the full grid.
-    Returns (words, nbits, trailing_skip, ns); ns lets the caller make
-    its ship-bits-or-coefficients decision in the same jit. Output is
-    bit-identical to the full-grid coder for every ns (compaction
-    preserves raster order; padded slots emit zero bits)."""
+    pays the 256-slot coder while a scene cut pays the full grid. The
+    top bucket of a multi-bucket ladder merges whole MBs when every MB's
+    bit bound fits MB_WORDS (known from the structure before any bit is
+    written), else its segments.
+    Returns (words, nbits, trailing_skip, ns, counts); ns lets the
+    caller make its ship-bits-or-coefficients decision in the same jit,
+    and counts = [coef_luma, coef_chroma, rung] says how many emitted
+    luma / chroma AC blocks hold a coefficient and which rung ran: the
+    bucket's index, or len(buckets) for the top bucket merging segments.
+    Output is bit-identical to the full-grid coder for every ns
+    (compaction preserves raster order; padded slots emit zero bits)."""
     s = _frame_structure(out)
     M = s["coded"].shape[0]
     if buckets is None:
@@ -850,22 +938,29 @@ def pack_p_slice_bits_active(out, word_cap: int = WORD_CAP_DEFAULT,
         A = buckets[0]
         words, nbits = _emit_slice_bits(
             s if A >= M else _compact_structure(s, A), word_cap)
-        return words, nbits, s["trailing"], ns
+        rung = jnp.int32(0)
+    else:
+        top = len(buckets) - 1
+        mb_fits = _mb_bits_bound(s) <= 32 * MB_WORDS
 
-    def _branch(A: int):
-        if A >= M:
-            return lambda _: _emit_slice_bits(s, word_cap)
-        return lambda _: _emit_slice_bits(_compact_structure(s, A), word_cap)
+        def _branch(i: int):
+            fits = mb_fits if i == top else None
+            if buckets[i] >= M:
+                return lambda _: _emit_slice_bits(s, word_cap, fits)
+            return lambda _: _emit_slice_bits(
+                _compact_structure(s, buckets[i]), word_cap, fits)
 
-    # the bucket switch counts as emission; each branch's compaction
-    # carries its own scope
-    with jax.named_scope("enc.entropy.emit"):
-        idx = jnp.clip(
-            jnp.searchsorted(jnp.asarray(buckets, jnp.int32), ns, side="left"),
-            0, len(buckets) - 1)
-        words, nbits = jax.lax.switch(idx, [_branch(b) for b in buckets],
-                                      jnp.int32(0))
-    return words, nbits, s["trailing"], ns
+        # the bucket switch counts as emission; each branch's compaction
+        # carries its own scope
+        with jax.named_scope("enc.entropy.emit"):
+            idx = jnp.clip(
+                jnp.searchsorted(jnp.asarray(buckets, jnp.int32), ns, side="left"),
+                0, top)
+            words, nbits = jax.lax.switch(
+                idx, [_branch(i) for i in range(len(buckets))], jnp.int32(0))
+        rung = jnp.where((idx == top) & ~mb_fits, top + 1, idx).astype(jnp.int32)
+    counts = jnp.stack([s["coef_luma"], s["coef_chroma"], rung])
+    return words, nbits, s["trailing"], ns, counts
 
 
 # ---------------------------------------------------------------------------

@@ -116,9 +116,11 @@ CAP_ROWS_DELTA = 4096
 NSCAP = 4096
 # Device-entropy downlink (full P frames): the slice-data BITSTREAM is
 # produced on device (device_cavlc.py) and fetched instead of multi-MB
-# coefficient tensors. The prefix fetch carries [nbits, trailing, nskip]
-# + the first BITS_PREFIX_WORDS words; bigger frames spill one extra
-# fetch; frames overflowing the word cap fall back to the dense path.
+# coefficient tensors. The prefix fetch carries BITS_META = [nbits,
+# trailing, nskip, coef_luma, coef_chroma, rung] + the first
+# BITS_PREFIX_WORDS words; bigger frames spill one extra fetch; frames
+# overflowing the word cap fall back to the dense path.
+BITS_META = 6
 BITS_PREFIX_WORDS = 1 << 16  # 256 KB: covers typical full-P slices in ONE fetch
 # Delta frames run the same device entropy coder activity-proportionally
 # (pack_p_sparse_entropy); the live-MB threshold and the rest of the
@@ -162,10 +164,12 @@ def _p_bits_step(y, u, v, qp, ref_y, ref_u, ref_v):
     not the grid). Dense header/buf ride along device-side only, as the
     overflow fallback (fetched on the rare nbits > cap frame)."""
     out = encode_frame_p_planes(y, u, v, ref_y, ref_u, ref_v, qp)
-    words, nbits, trailing, _ns = pack_p_slice_bits_active(out, BITS_WORD_CAP)
+    words, nbits, trailing, _ns, counts = pack_p_slice_bits_active(
+        out, BITS_WORD_CAP)
     with jax.named_scope("enc.downlink"):
         nskip = out["skip"].sum().astype(jnp.int32)
-        meta = jnp.stack([nbits, trailing, nskip]).astype(jnp.uint32)
+        meta = jnp.concatenate(
+            [jnp.stack([nbits, trailing, nskip]), counts]).astype(jnp.uint32)
         prefix = jnp.concatenate([meta, words[:BITS_PREFIX_WORDS]])
         header, buf = pack_p_compact(out)
     return prefix, words, header, buf, out["recon_y"], out["recon_u"], out["recon_v"]
@@ -510,6 +514,13 @@ def _p_scatter_multi_step2(packed_a, packed_b, qps, sy, su, sv, py, pu, pv,
 _fetch_rest = fetch_rest
 
 
+def _lowering_shape(x):
+    """What a jitted call sees of argument x, for lowering ahead of it: its
+    shape and dtype, and its sharding where the array is committed."""
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding)
+
+
 FrameStats = _FrameStats  # shared definition (models/stats.py)
 
 
@@ -558,6 +569,10 @@ class _Pending:
     ltr_ref: int | None = None   # predict from long-term reference j
     mark_ltr: int | None = None  # mark the previous frame as LT index k
     mmco_evict: tuple = ()       # MMCO 1 diffs for stale short-terms
+    # device-entropy full-P: the meta prefix's coefficient-carrying
+    # (luma, chroma AC) block counts and emission rung
+    coef_blocks: tuple = (0, 0)
+    bits_rung: int = -1
 
 
 class TPUH264Encoder:
@@ -806,6 +821,13 @@ class TPUH264Encoder:
         self._up_buckets = (0,) + self._delta_buckets
         self._up_batch_buckets = (0,) + self.BATCH_BUCKETS
         self._step2_cache: dict = {}
+        # a tile-cache step compiles for minutes on a TPU: there the first
+        # frame that needs one starts its build on a background thread
+        # and takes the full-frame path until it is built (same bytes)
+        self._step2_off_stream = jax.default_backend() == "tpu"
+        self._step2_built: dict = {}  # (kind, bucket, cbucket, take) -> fn
+        self._step2_building: set = set()
+        self._step2_lock = threading.Lock()
         self._ltr_probe: object = ()  # per-frame memo, see _classify
         self.link_bytes = LinkByteCounter()
         # last-seen tile-cache totals, for per-frame telemetry deltas
@@ -1371,6 +1393,76 @@ class TPUH264Encoder:
             self._step2_cache[key] = fn
         return fn
 
+    def _step2_ready(self, kind: str, bucket: int, cbucket: int, args,
+                     take: int = 0) -> bool:
+        """Whether the tile-cache step for this key runs without compiling
+        in the stream. Where steps build off the stream and this one is
+        not built yet, start its build (once) from the shapes of the
+        arguments `args()` returns, and answer False: the caller takes a
+        path that is already built."""
+        fn = self._get_step2(kind, bucket, cbucket)
+        key = (kind, bucket, cbucket, take)
+        with self._step2_lock:
+            if self._step2_built.get(key) is fn:
+                return True
+            if key in self._step2_building:
+                return False
+            self._step2_building.add(key)
+        specs = [_lowering_shape(a) for a in args()]
+
+        def build() -> None:
+            fn.lower(*specs).compile()
+            with self._step2_lock:
+                self._step2_built[key] = fn
+                self._step2_building.discard(key)
+
+        # a failed build stays "building": its frames keep the full path
+        threading.Thread(target=build, name=f"step2-build-{kind}",
+                         daemon=True).start()
+        return False
+
+    def _packed2_shape(self, bucket: int, cbucket: int) -> tuple:
+        """Shape of _pack_tiles2's buffer for one (bucket, cbucket)."""
+        tw, e = self._tile_w, np.zeros(0, np.int32)
+        return self._pack_tiles2(
+            np.zeros((0, 16, tw), np.uint8), np.zeros((0, 8, tw // 2), np.uint8),
+            np.zeros((0, 8, tw // 2), np.uint8), e, e, np.zeros((0, 2), np.int32),
+            bucket, cbucket).shape
+
+    def _delta_step_ready(self, payload, idr: bool) -> bool:
+        """_step2_ready for the step a tile-cache delta frame dispatches
+        alone (_run_step_delta, or a group of one in _flush_batch)."""
+        if not self._step2_off_stream:
+            return True
+        up_idx, _pool_dst, pairs = payload
+        bucket = next(b for b in self._up_buckets if b >= len(up_idx))
+        cbucket = next(cb for cb in self._copy_buckets if cb >= len(pairs))
+
+        def args():
+            return (np.zeros(self._packed2_shape(bucket, cbucket), np.uint8),
+                    np.int32(self.qp), *self._src, *self._get_pool(),
+                    *(() if idr else self._ref))
+
+        return self._step2_ready("i" if idr else "p", bucket, cbucket, args)
+
+    def _group_step_ready(self, group) -> bool:
+        """_step2_ready for _flush_batch's grouped scan over `group`."""
+        if not self._step2_off_stream:
+            return True
+        bucket = next(b for b in self._up_batch_buckets
+                      if b >= max(len(g[4]) for g in group))
+        cbucket = next(cb for cb in self._copy_buckets
+                       if cb >= max(len(g[6]) for g in group))
+
+        def args():
+            n, half = self._packed2_shape(bucket, cbucket)[0], len(group) // 2
+            return (np.zeros((half, n), np.uint8),
+                    np.zeros((len(group) - half, n), np.uint8),
+                    np.zeros(len(group), np.int32),
+                    *self._src, *self._get_pool(), *self._ref)
+
+        return self._step2_ready("pk", bucket, cbucket, args, take=len(group))
+
     def _seed_pool(self, frame: np.ndarray, idx: np.ndarray,
                    hashes: np.ndarray | None = None) -> None:
         """After an over-budget full upload: commit the dirty tiles to
@@ -1381,9 +1473,17 @@ class TPUH264Encoder:
         pass already computed them — re-hashing here would repeat the
         exact redundant read the fused front-end removed)."""
         up_idx, pool_dst, _pairs = self._tcache.split(frame, idx, hashes=hashes)
+        self._fill_pool(pool_dst, up_idx)
+
+    def _fill_pool(self, pool_dst: np.ndarray, up_idx: np.ndarray,
+                   sbucket: int | None = None) -> None:
+        """Pool slots `pool_dst` <- tiles `up_idx` of the resident source
+        planes, gathered device-side (padded to `sbucket` pairs, by
+        default the smallest copy bucket that holds them)."""
         if not len(up_idx):
             return
-        sbucket = next(cb for cb in self._copy_buckets if cb >= len(up_idx))
+        if sbucket is None:
+            sbucket = next(cb for cb in self._copy_buckets if cb >= len(up_idx))
         pr = np.zeros((sbucket, 2), np.int32)
         pr[:, 0] = self.tile_cache_slots  # scratch padding
         pr[: len(up_idx), 0] = pool_dst
@@ -1586,6 +1686,8 @@ class TPUH264Encoder:
             while i < len(pend):
                 t_d0 = time.perf_counter()
                 take = next((s for s in self._batch_sizes if len(pend) - i >= s), 1)
+                if take > 1 and tc and not self._group_step_ready(pend[i : i + take]):
+                    take = 1  # each member's single step is built
                 group = pend[i : i + take]
                 i += take
                 if take == 1:
@@ -1847,6 +1949,11 @@ class TPUH264Encoder:
         classify_ms = (time.perf_counter() - t0) * 1e3
         if telemetry.enabled:
             self._emit_classify_telemetry(kind, dirty_idx)
+        # a tile-cache delta whose step is still building goes whole: the
+        # full-frame path codes the same frame, and the pool slots its
+        # split assigned are filled from the resident planes after it
+        whole = (kind == "delta" and self._tcache is not None
+                 and not self._delta_step_ready(dirty_idx, idr))
         batch_full = False
         orig_qp = self.qp
         # a scene CUT is the transition into a full-frame change; during
@@ -1922,6 +2029,7 @@ class TPUH264Encoder:
         elif (
             not idr
             and kind == "delta"
+            and not whole
             and self.frame_batch > 1
             and (len(dirty_idx[0]) if self._tcache is not None else len(dirty_idx))
             <= self.BATCH_BUCKETS[-1]
@@ -1962,7 +2070,7 @@ class TPUH264Encoder:
                 self._t_disp0 = 0.0
                 hdr_d = None
                 if idr:
-                    if kind == "delta":
+                    if kind == "delta" and not whole:
                         prefix_d, hdr_d, buf_d, ry, ru, rv = self._run_step_delta(
                             frame, dirty_idx, idr=True
                         )
@@ -2000,7 +2108,7 @@ class TPUH264Encoder:
                         ltr_ref = ltr_hit[0]
                         n_up = len(ltr_hit[1])
                         self.ltr_restores += 1
-                    elif kind == "delta":
+                    elif kind == "delta" and not whole:
                         prefix_d, hdr_d, buf_d, ry, ru, rv = self._run_step_delta(
                             frame, dirty_idx, idr=False
                         )
@@ -2039,6 +2147,11 @@ class TPUH264Encoder:
                 rec.convert_ms = self._t_conv_ms
                 rec.h2d_ms = self._t_h2d_ms
                 rec.up_ms = classify_ms + (rec.t_disp - t_d0) * 1e3
+                if whole:
+                    # one gather size for every such frame: the one a
+                    # full-frame seed of the whole grid already built
+                    self._fill_pool(dirty_idx[1], dirty_idx[0],
+                                    self._copy_buckets[-1])
                 # over-budget delta that fell back to full: seed the tile
                 # pool from the now-resident planes so the NEXT frame of
                 # a sustained scroll fits the delta path via remaps.
@@ -2186,6 +2299,9 @@ class TPUH264Encoder:
             h2d_ms=rec.h2d_ms,
             downlink_mode=mode,
             handoff_wait_ms=handoff_ms,
+            coef_blocks_luma=rec.coef_blocks[0],
+            coef_blocks_chroma=rec.coef_blocks[1],
+            bits_rung=rec.bits_rung,
             upload_kind="delta" if rec.kind == "pd" else "full",
             dirty_frac=(min(1.0, dirty / self._ntiles)
                         if rec.kind == "pd" else 1.0),
@@ -2280,10 +2396,15 @@ class TPUH264Encoder:
         slice header, done — no coefficient unpack, no host CAVLC."""
         step_ms, t_ready = self._wait_step(rec, rec.prefix_d)
         with tracer.span("fetch", pts=rec.meta):
-            arr = np.asarray(rec.prefix_d)  # uint32: nbits, trailing, nskip, words...
+            arr = np.asarray(rec.prefix_d)  # uint32: BITS_META, words...
         fetch_ms = (time.perf_counter() - t_ready) * 1e3
         self.link_bytes.add("down_bits", arr.nbytes)
         nbits, trailing, skipped = int(arr[0]), int(arr[1]), int(arr[2])
+        rec.coef_blocks = (int(arr[3]), int(arr[4]))
+        rec.bits_rung = int(arr[5])
+        tracer.value("coef_blocks_luma", rec.coef_blocks[0])
+        tracer.value("coef_blocks_chroma", rec.coef_blocks[1])
+        tracer.value("bits_rung", rec.bits_rung)
         if nbits > BITS_WORD_CAP * 32:
             # pathological frame overflowed the bit buffer: dense fallback
             header = np.asarray(rec.hdr_d)
@@ -2298,7 +2419,7 @@ class TPUH264Encoder:
             return (au, int(pfc.skip.sum()), t1, tu, time.perf_counter(),
                     "dense", step_ms, fetch_ms)
         need = (nbits + 31) // 32
-        words = arr[3 : 3 + min(need, BITS_PREFIX_WORDS)]
+        words = arr[BITS_META : BITS_META + min(need, BITS_PREFIX_WORDS)]
         if need > BITS_PREFIX_WORDS:  # spill: one extra fetch
             with tracer.span("bits_fetch", pts=rec.meta):
                 rest = _fetch_rest(rec.words_d, need, BITS_PREFIX_WORDS)

@@ -1206,7 +1206,7 @@ def pack_p_sparse_entropy(out, nscap: int, cap_rows: int,
         fused, dense, buf = pack_p_sparse_var(out, nscap, cap_rows)
     else:
         fused, dense, buf = pack_p_sparse_packed(out, nscap, cap_rows, density_pct)
-    words, nbits, trailing, ns = pack_p_slice_bits_active(
+    words, nbits, trailing, ns, _counts = pack_p_slice_bits_active(
         out, word_cap=bits_words, buckets=buckets)
     nskip = out["skip"].reshape(-1).sum().astype(jnp.int32)
     use_bits = (

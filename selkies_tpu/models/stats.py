@@ -99,3 +99,10 @@ class FrameStats:
     # emits in submission order, so a finished frame waits for the next
     # call to reach it); 0 for static frames, which complete inline
     handoff_wait_ms: float = 0.0
+    # device-entropy full-P frames (device_cavlc.pack_p_slice_bits_active):
+    # the luma 4x4 and chroma AC blocks that hold a coefficient, and the
+    # emission rung the device took (its counts[2]; -1 for
+    # frames that did not ship device bits this way)
+    coef_blocks_luma: int = 0
+    coef_blocks_chroma: int = 0
+    bits_rung: int = -1

@@ -136,6 +136,19 @@ black-box bundles stay greppable):
 The solo video path's submit, classify, step, fetch, unpack, pack,
 bits_fetch, send and ws-send spans carry the frame's ``pts``.
 
+Per-frame counters (`tracer.value(name, v)`; the summary gives their
+count, mean, min, median and max, and a histogram when they take at
+most 16 distinct values):
+
+  encoder completion workers (models/h264/encoder.py), device-entropy
+  full-P frames only:
+    coef_blocks_luma    luma 4x4 blocks of the frame that hold a
+                        coefficient (FrameStats.coef_blocks_luma)
+    coef_blocks_chroma  chroma AC blocks that hold a coefficient
+    bits_rung           the emission rung the frame took on device
+                        (device_cavlc.pack_p_slice_bits_active;
+                        FrameStats.bits_rung)
+
 Device scopes (`jax.named_scope`, models/h264/): inside the jitted steps
 every op carries the innermost of these names in its op-name path, so a
 device trace splits one step executable by stage. The vocabulary is
@@ -220,6 +233,7 @@ class Tracer:
         self.capacity = capacity
         self._ring: deque = deque(maxlen=capacity)
         self._agg: dict[str, list] = {}  # name -> [count, total, min, max, ewma]
+        self._vals: dict[str, deque] = {}  # counter name -> recent values
         self._lock = threading.Lock()
         self._epoch = time.perf_counter()
 
@@ -235,6 +249,7 @@ class Tracer:
         with self._lock:
             self._ring.clear()
             self._agg.clear()
+            self._vals.clear()
             self._epoch = time.perf_counter()
 
     # -- recording -----------------------------------------------------
@@ -251,6 +266,16 @@ class Tracer:
         if self.enabled:
             now = time.perf_counter()
             self._record(name, now, now)
+
+    def value(self, name: str, v) -> None:
+        """One observation of a per-frame counter (the last `capacity`
+        are kept for the summary)."""
+        if self.enabled:
+            with self._lock:
+                vals = self._vals.get(name)
+                if vals is None:
+                    vals = self._vals[name] = deque(maxlen=self.capacity)
+                vals.append(v)
 
     def _record(self, name: str, t0: float, t1: float) -> None:
         dur = t1 - t0
@@ -280,9 +305,11 @@ class Tracer:
     # -- export --------------------------------------------------------
 
     def summary(self) -> dict:
-        """Per-stage aggregates in milliseconds (stats-tracer view)."""
+        """Per-stage aggregates in milliseconds (stats-tracer view), and
+        per counter its count, mean, min, median and max (``hist``, value
+        -> count, where it took at most 16 distinct values)."""
         with self._lock:
-            return {
+            out = {
                 name: {
                     "count": a[0],
                     "mean_ms": round(a[1] / a[0] * 1e3, 3),
@@ -292,6 +319,16 @@ class Tracer:
                 }
                 for name, a in self._agg.items()
             }
+            vals = {name: sorted(v) for name, v in self._vals.items()}
+        for name, v in vals.items():
+            n = len(v)
+            out[name] = {
+                "count": n, "mean": round(sum(v) / n, 3), "min": v[0],
+                "median": (v[(n - 1) // 2] + v[n // 2]) / 2, "max": v[-1]}
+            distinct = sorted(set(v))
+            if len(distinct) <= 16:
+                out[name]["hist"] = {str(d): v.count(d) for d in distinct}
+        return out
 
     def chrome_trace(self) -> str:
         """Trace-event JSON for chrome://tracing / Perfetto (latency-

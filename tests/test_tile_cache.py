@@ -263,3 +263,45 @@ def test_over_budget_scroll_stays_on_delta_path():
     snap_p = enc_p.link_bytes.snapshot()
     assert snap_p.get("up_delta", 0) == 0  # plain encoder full-uploads ALL of them
     assert snap["up_full"] < snap_p["up_full"], "no full uploads were saved"
+
+
+@pytest.mark.parametrize("frame_batch,device_entropy",
+                         [(1, False), (4, False), (4, True)])
+def test_steps_built_off_the_stream_bitexact(frame_batch, device_entropy):
+    """Where tile-cache steps build off the stream (a TPU, where each
+    compiles for minutes), the deltas that need an unbuilt step take the
+    full-frame path while it builds on a background thread, and the
+    remaps resume once it is built; the stream is byte-identical to the
+    same encoder compiling in the stream, LTR marking included."""
+    import time
+
+    frames = scroll_trace(W, 256, 16, bands=5)
+
+    def built(enc):
+        t0 = time.monotonic()
+        while enc._step2_building and time.monotonic() - t0 < 300:
+            time.sleep(0.05)
+
+    def encode(off_stream):
+        enc = TPUH264Encoder(W, 256, qp=26, frame_batch=frame_batch,
+                             pipeline_depth=2, tile_cache=512,
+                             packed_downlink=True,
+                             device_entropy=device_entropy)
+        enc._step2_off_stream = off_stream
+        outs = []
+        for i, f in enumerate(frames):
+            if i == len(frames) // 2:
+                built(enc)
+            outs.extend(enc.submit(f))
+        outs.extend(enc.flush())
+        built(enc)  # no build left running past the test
+        return outs, enc
+
+    ref, _ = encode(False)
+    outs, enc = encode(True)
+    assert b"".join(a for a, _, _ in outs) == b"".join(a for a, _, _ in ref)
+    kinds = [st.upload_kind for _, st, _ in outs]
+    half = len(frames) // 2
+    assert "full" in kinds[2:half] and "delta" not in kinds[1:half], kinds
+    assert "delta" in kinds[half:], kinds
+    assert enc._step2_built and enc._tcache.hits > 0

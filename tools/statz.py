@@ -336,9 +336,14 @@ def render(rollup: dict, events: list[dict]) -> str:
     trace = rollup.get("trace") or {}
     if trace:
         rows = [(name, s["count"], s["mean_ms"], s["max_ms"], s["ewma_ms"])
-                for name, s in sorted(trace.items())]
+                for name, s in sorted(trace.items()) if "mean_ms" in s]
         out.append("\n== tracer summary (ms)\n" + _table(
             rows, ("span", "count", "mean", "max", "ewma")))
+        rows = [(name, s["count"], s["mean"], s["median"], s["max"])
+                for name, s in sorted(trace.items()) if "median" in s]
+        if rows:
+            out.append("\n== tracer counters\n" + _table(
+                rows, ("counter", "count", "mean", "median", "max")))
 
     if events:
         out.append(f"\n== black-box events ({len(events)}, newest last; "
